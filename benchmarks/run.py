@@ -54,6 +54,9 @@ def main() -> None:
     ap.add_argument("--only", default=None, help="substring filter on bench name")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     os.makedirs("experiments/results", exist_ok=True)
     failed = []
     print("name,us_per_call,derived")

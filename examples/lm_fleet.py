@@ -31,9 +31,11 @@ from repro.core.federated import (LM_SEQ_LEN, LM_VOCAB, FogNode, Trainer,
                                   lm_config)
 from repro.core.model_adapter import excluded_paths
 from repro.data.lm import lm_federated_split, make_lm_dataset
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main(argv=None):
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--devices", type=int, default=16)
     ap.add_argument("--rounds", type=int, default=4)

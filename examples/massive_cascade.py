@@ -15,9 +15,11 @@ from repro.core.federated import (EdgeDevice, FederatedALConfig, FogNode,
                                   Trainer, run_federated_round)
 from repro.data.digits import make_digit_dataset
 from repro.data.federated_split import federated_split
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main(argv=None):
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--devices", type=int, default=10)
     ap.add_argument("--images-per-device", type=int, default=40)

@@ -31,10 +31,12 @@ from repro.core.federated import (FogNode, Trainer, massive_config,
                                   MASSIVE_SAMPLES_PER_DEVICE)
 from repro.data.digits import make_digit_dataset
 from repro.data.federated_split import federated_split
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_device_mesh
 
 
 def main(argv=None):
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true",
                     help="tiny fleet/budgets (CI smoke-test sizing)")

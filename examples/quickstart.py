@@ -18,9 +18,11 @@ from repro.core import counters
 from repro.core.federated import FederatedALConfig, run_federated_round, Trainer
 from repro.data.digits import make_digit_dataset
 from repro.data.federated_split import federated_split
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main(argv=None):
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true",
                     help="tiny fleet/budgets (CI smoke-test sizing)")

@@ -35,9 +35,11 @@ from repro.core.fleet import FleetConfig
 from repro.core.stream import stream_telemetry
 from repro.data.digits import make_digit_dataset
 from repro.data.federated_split import dirichlet_split
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main(argv=None):
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--devices", type=int, default=32)
     ap.add_argument("--events", type=int, default=6,

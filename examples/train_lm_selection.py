@@ -30,6 +30,7 @@ from repro.core.engine import EdgeEngine
 from repro.core.federated import FogNode, Trainer, lm_config
 from repro.core.model_adapter import DecoderLMAdapter
 from repro.data.lm import lm_federated_split, make_lm_dataset
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import ModelConfig
 
 
@@ -51,6 +52,7 @@ def small_decoder(*, vocab: int, seq_len: int, n_layers: int = 2) -> ModelConfig
 
 
 def main(argv=None):
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--devices", type=int, default=8)
     ap.add_argument("--rounds", type=int, default=3)

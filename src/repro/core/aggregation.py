@@ -219,12 +219,17 @@ AGG_IMPLS = ("auto", "ref", "pallas", "pallas_interpret")
 def resolve_aggregate_impl(impl: Optional[str]) -> str:
     """``auto`` → the fused Pallas kernel on TPU, the jnp reference
     elsewhere (interpret-mode Pallas is functional but slow on CPU — the
-    same policy as ``engine.resolve_scorer``)."""
+    same policy as ``engine.resolve_scorer``).  An explicit ``"pallas"``
+    off-TPU raises rather than quietly interpreting."""
     if impl in (None, "auto"):
         return "pallas" if jax.default_backend() == "tpu" else "ref"
     if impl not in AGG_IMPLS:
         raise ValueError(
             f"unknown aggregate_impl {impl!r}: use {' | '.join(AGG_IMPLS)}")
+    if impl == "pallas" and jax.default_backend() != "tpu":
+        raise ValueError(
+            f"aggregate_impl='pallas' compiles for TPU, but the backend is "
+            f"{jax.default_backend()!r}: use 'pallas_interpret' or 'ref'")
     return impl
 
 
@@ -256,7 +261,7 @@ def aggregate_stacked(stacked, w, *, impl: str = "ref", segment_ids=None,
     return fused_aggregate(
         stacked, w, normalize=False, segment_ids=segment_ids,
         num_segments=num_segments, out_dtype=out_dtype,
-        interpret=True if impl == "pallas_interpret" else None)
+        interpret=impl == "pallas_interpret")
 
 
 def weighted_average_stacked(stacked, weights, *, mask=None,

@@ -332,7 +332,6 @@ def _get_async_jit(engine, events: int, aggregation: str, comms_key,
     from repro.core.federated import _donate_argnums
 
     def build():
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
         # non-None comms_key == lossy wire: a real codec OR a sub-f32
@@ -868,7 +867,7 @@ def _get_async_jit(engine, events: int, aggregation: str, comms_key,
 
         if on_mesh:
             dev = _fleet_spec(mesh)
-            events_all = shard_map(
+            events_all = jax.shard_map(
                 events_all, mesh=mesh,
                 # fkeys / frates / gfactor / group_ids / sync_flags /
                 # skeys / srates / svec replicate: fault draws, the
@@ -881,7 +880,7 @@ def _get_async_jit(engine, events: int, aggregation: str, comms_key,
                           P()),
                 # recs and the fog model are replicated (all_gather / psum
                 # results); state stays sharded
-                out_specs=(dev, P(), P()), check_rep=False)
+                out_specs=(dev, P(), P()), check_vma=False)
 
         return jax.jit(events_all, donate_argnums=_donate_argnums(0))
 

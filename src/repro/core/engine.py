@@ -57,7 +57,6 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core import acquisition as acq
@@ -205,8 +204,15 @@ def stack_device_data(device_data: Sequence):
 
 
 def resolve_scorer(mode: str) -> str:
+    """``auto`` → the fused Pallas kernel on TPU, the jnp oracle elsewhere.
+    An explicit ``"pallas"`` off-TPU raises: the kernel would otherwise run
+    in interpret mode, which only ``"pallas_interpret"`` asks for."""
     if mode in (None, "auto"):
         return "pallas" if jax.default_backend() == "tpu" else "jnp"
+    if mode == "pallas" and jax.default_backend() != "tpu":
+        raise ValueError(
+            f"scorer='pallas' compiles for TPU, but the backend is "
+            f"{jax.default_backend()!r}: use 'pallas_interpret' or 'jnp'")
     return mode
 
 
@@ -214,7 +220,7 @@ def _make_score_fn(acquisition_fn: str, scorer: str):
     """logp [T, W, C] → scores [W]; higher = more informative."""
     scorer = resolve_scorer(scorer)
     if scorer in ("pallas", "pallas_interpret") and acquisition_fn in _FUSED_SCORES:
-        interpret = scorer == "pallas_interpret" or jax.default_backend() != "tpu"
+        interpret = scorer == "pallas_interpret"
 
         def score(logp):
             ent, bald, vr = acquisition_scores_fused(logp, interpret=interpret)
@@ -488,10 +494,10 @@ class EdgeEngine:
                 # local devices; no collectives needed for a plain round.
                 dev = _fleet_spec(mesh)
                 n_extra = 4 if record_curves else 2
-                round_all = shard_map(
+                round_all = jax.shard_map(
                     round_all, mesh=mesh,
                     in_specs=(dev, dev, dev) + (P(),) * n_extra,
-                    out_specs=(dev, dev), check_rep=False)
+                    out_specs=(dev, dev), check_vma=False)
 
             from repro.core.federated import _donate_argnums
             return jax.jit(round_all, donate_argnums=_donate_argnums(0))
@@ -1157,7 +1163,7 @@ class EdgeEngine:
                 keys_spec = _fleet_spec(mesh, None)
                 mask_spec = (P() if mask_mode == "bernoulli"
                              else _fleet_spec(mesh, None))
-                rounds_all = shard_map(
+                rounds_all = jax.shard_map(
                     rounds_all, mesh=mesh,
                     # live_arg / fkeys / frates / gfactor / group_ids /
                     # sync_flags / fog_keys are replicated: liveness rows,
@@ -1168,7 +1174,7 @@ class EdgeEngine:
                               P(), P(), P(), P(), P(), P(), P()),
                     # recs and the aggregated model are replicated
                     # (all_gather / psum results), state stays sharded
-                    out_specs=(dev, P(), P()), check_rep=False)
+                    out_specs=(dev, P(), P()), check_vma=False)
 
             from repro.core.federated import _donate_argnums
             return jax.jit(rounds_all, donate_argnums=_donate_argnums(0))

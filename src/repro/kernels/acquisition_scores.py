@@ -4,14 +4,17 @@ The paper's edge-side hot loop is: T stochastic forwards over a pool window,
 then per-point uncertainty statistics (Eqs. 2–4). Computed naively, the
 [T, N, C] log-prob tensor is read from HBM once per statistic (entropy,
 BALD, VR) — 3× the traffic of one pass. This kernel fuses all three into a
-single VMEM-resident pass over pool tiles: for each [T, bn, C] tile it
+single VMEM-resident pass over pool tiles: for each [T, C, bn] tile it
 computes the MC-mean posterior once and emits entropy / BALD / VR together.
 
-TPU adaptation (DESIGN.md §5): class axis C is padded to the 128-lane width
-and pool tiles to 8-sublane multiples; the T reduction happens in VREGs.
+TPU adaptation: the pool axis rides the 128-lane width and the class axis
+the sublanes (C padded to a multiple of 8), so every per-point statistic
+is a sublane reduction that lands lane-dense in a ``[1, bn]`` output row —
+the layout Mosaic needs for any pool size.  The T reduction happens in
+VREGs.
 
-Grid: (N_pad // bn,). BlockSpecs keep [T, bn, C_pad] in VMEM
-(T=16, bn=128, C=128 → 1 MB fp32).
+Grid: (N_pad // bn,). BlockSpecs keep [T, C_pad, bn] in VMEM
+(T=16, C=10 → C_pad=16, bn=128 → 128 KB fp32).
 """
 from __future__ import annotations
 
@@ -25,48 +28,45 @@ _EPS = 1e-10
 _NEG = -1e30
 
 
-def _kernel(logp_ref, ent_ref, bald_ref, vr_ref, *, T: int, n_classes: int):
-    logp = logp_ref[...].astype(jnp.float32)             # [T, bn, C_pad]
-    C_pad = logp.shape[-1]
+def _kernel(logp_ref, ent_ref, bald_ref, vr_ref, *, n_classes: int):
+    logp = logp_ref[...]                                 # [T, C_pad, bn] f32
     # mask padded classes: contribute 0 probability
-    class_ok = jax.lax.broadcasted_iota(jnp.int32, (1, 1, C_pad), 2) < n_classes
+    class_ok = jax.lax.broadcasted_iota(jnp.int32, logp.shape, 1) < n_classes
     logp = jnp.where(class_ok, logp, _NEG)
 
-    p = jnp.exp(logp)                                    # [T, bn, C]
-    pbar = jnp.mean(p, axis=0)                           # [bn, C]
+    p = jnp.exp(logp)                                    # [T, C, bn]
+    pbar = jnp.mean(p, axis=0)                           # [C, bn]
     log_pbar = jnp.log(pbar + _EPS)
 
-    ent = -jnp.sum(jnp.where(class_ok[0], pbar * log_pbar, 0.0), axis=-1)   # [bn]
+    ent = -jnp.sum(jnp.where(class_ok[0], pbar * log_pbar, 0.0), axis=0,
+                   keepdims=True)                                        # [1, bn]
     exp_ent = -jnp.mean(
-        jnp.sum(jnp.where(class_ok, p * logp, 0.0), axis=-1), axis=0)        # [bn]
-    vr = 1.0 - jnp.max(pbar, axis=-1)                                        # [bn]
+        jnp.sum(jnp.where(class_ok, p * logp, 0.0), axis=1, keepdims=True),
+        axis=0)                                                          # [1, bn]
+    vr = 1.0 - jnp.max(pbar, axis=0, keepdims=True)                      # [1, bn]
 
-    ent_ref[...] = ent[None, :]
-    bald_ref[...] = (ent - exp_ent)[None, :]
-    vr_ref[...] = vr[None, :]
+    ent_ref[...] = ent
+    bald_ref[...] = ent - exp_ent
+    vr_ref[...] = vr
 
 
 def acquisition_scores_fused(log_probs, *, block_n: int = 128,
                              interpret: bool = False):
     """log_probs: [T, N, C] → (entropy [N], bald [N], vr [N]) in one pass."""
     T, N, C = log_probs.shape
-    C_pad = max(128, -(-C // 128) * 128)
+    C_pad = -(-C // 8) * 8
     N_pad = -(-N // block_n) * block_n
-    x = jnp.pad(log_probs, ((0, 0), (0, N_pad - N), (0, C_pad - C)),
-                constant_values=_NEG)
-    nb = N_pad // block_n
-
-    out_shape = [jax.ShapeDtypeStruct((nb, block_n), jnp.float32)] * 3
-    grid = (nb,)
-    in_specs = [pl.BlockSpec((T, block_n, C_pad), lambda i: (0, i, 0))]
-    out_specs = [pl.BlockSpec((1, block_n), lambda i: (i, 0))] * 3
+    x = jnp.pad(jnp.swapaxes(log_probs.astype(jnp.float32), 1, 2),
+                ((0, 0), (0, C_pad - C), (0, N_pad - N)),
+                constant_values=_NEG)                    # [T, C_pad, N_pad]
 
     ent, bald, vr = pl.pallas_call(
-        functools.partial(_kernel, T=T, n_classes=C),
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=out_specs,
-        out_shape=out_shape,
+        functools.partial(_kernel, n_classes=C),
+        grid=(N_pad // block_n,),
+        in_specs=[pl.BlockSpec((T, C_pad, block_n), lambda i: (0, 0, i))],
+        out_specs=[pl.BlockSpec((1, block_n), lambda i: (0, i))] * 3,
+        out_shape=[jax.ShapeDtypeStruct((1, N_pad), jnp.float32)] * 3,
         interpret=interpret,
+        name="acquisition_scores",
     )(x)
-    return ent.reshape(N_pad)[:N], bald.reshape(N_pad)[:N], vr.reshape(N_pad)[:N]
+    return ent[0, :N], bald[0, :N], vr[0, :N]

@@ -34,8 +34,9 @@ parity with ``kernels.ref.fused_agg_ref`` is to float tolerance (≤1e-5
 fp32), pinned by tests/test_fused_aggregation.py.
 
 On CPU (CI) the kernel runs in Pallas interpret mode — functional, not
-fast; the TPU lowering is unvalidated on real hardware (ROADMAP:
-"validated on real TPU hardware").  ``interpret=None`` auto-selects.
+fast.  ``tests/test_tpu_compile.py`` compiles it for a described v5e and
+``chip_smoke.py`` checks it against the reference on a TPU.
+``interpret=None`` auto-selects.
 """
 from __future__ import annotations
 
@@ -53,6 +54,13 @@ def _ceil_to(n: int, m: int) -> int:
     return max(m, -(-n // m) * m)
 
 
+def _dot(a, b):
+    # full f32 contraction on the MXU: Mosaic's default f32 matmul may take
+    # reduced-precision passes, which would round weights and scales
+    return jnp.dot(a, b, precision=jax.lax.Precision.HIGHEST,
+                   preferred_element_type=jnp.float32)
+
+
 def _kernel(x_ref, meta_ref, scales_ref, lid_ref, out_ref, *,
             kind: str, rate: float, normalize: bool, quantized: bool):
     meta = meta_ref[...]                                  # [8, Dp] f32
@@ -68,18 +76,16 @@ def _kernel(x_ref, meta_ref, scales_ref, lid_ref, out_ref, *,
     w = raw * dec * mask                                  # [1, Dp]
 
     Gp, Dp = out_ref.shape[0], w.shape[1]
-    rows = jax.lax.broadcasted_iota(jnp.float32, (Gp, Dp), 0)
-    onehot = (rows == segf).astype(jnp.float32)           # [Gp, Dp]
+    rows = jax.lax.broadcasted_iota(jnp.int32, (Gp, Dp), 0)
+    onehot = (rows == segf.astype(jnp.int32)).astype(jnp.float32)  # [Gp, Dp]
 
     if normalize:
         # masked_normalize, segment form, formula-for-formula: per-segment
         # Σw / Σm / size via one-hot matmuls, gathered back per row by the
         # transpose matmul (flat mode is the 1-segment special case)
         def seg_tot(v):                                   # [1, Dp] → [1, Dp]
-            tot = jnp.dot(onehot, v.T,
-                          preferred_element_type=jnp.float32)     # [Gp, 1]
-            return jnp.dot(tot.T, onehot,
-                           preferred_element_type=jnp.float32)    # [1, Dp]
+            tot = _dot(onehot, v.T)                       # [Gp, 1]
+            return _dot(tot.T, onehot)                    # [1, Dp]
 
         wsum = seg_tot(w)
         msum = seg_tot(mask)
@@ -96,14 +102,12 @@ def _kernel(x_ref, meta_ref, scales_ref, lid_ref, out_ref, *,
         # leaf-id row — dequantize stays on the MXU, no per-column gather
         lid = lid_ref[0:1, :]                             # [1, bn] f32 ids
         Lp = scales_ref.shape[1]
-        lrows = jax.lax.broadcasted_iota(jnp.float32, (Lp, lid.shape[1]), 0)
-        sel = (lrows == lid).astype(jnp.float32)          # [Lp, bn]
-        scale = jnp.dot(scales_ref[...], sel,
-                        preferred_element_type=jnp.float32)       # [Dp, bn]
+        lrows = jax.lax.broadcasted_iota(jnp.int32, (Lp, lid.shape[1]), 0)
+        sel = (lrows == lid.astype(jnp.int32)).astype(jnp.float32)  # [Lp, bn]
+        scale = _dot(scales_ref[...], sel)                # [Dp, bn]
         val = val * scale
 
-    out_ref[...] = jnp.dot(onehot * alpha, val,
-                           preferred_element_type=jnp.float32)    # [Gp, bn]
+    out_ref[...] = _dot(onehot * alpha, val)              # [Gp, bn]
 
 
 def fused_aggregate(stacked, weights, *, staleness=None, mask=None,
@@ -206,6 +210,7 @@ def fused_aggregate(stacked, weights, *, staleness=None, mask=None,
         out_specs=pl.BlockSpec((Gp, bn), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((Gp, N_pad), jnp.float32),
         interpret=interpret,
+        name="fused_aggregation",
     )(x, meta, smat, lid)
 
     res = out[:G, :N]

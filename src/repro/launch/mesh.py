@@ -2,80 +2,12 @@
 
 Defined as FUNCTIONS so importing this module never touches jax device
 state (the dry-run sets XLA_FLAGS before any jax init; smoke tests must
-keep seeing 1 device).
-
-Also home of the version-portable mesh-context helpers (``make_auto_mesh``
-/ ``use_mesh``): the supported jax range (0.4.x–0.5.x, see pyproject)
-moved the "activate a mesh so sharding hints resolve" API three times
-(``with mesh:`` → ``jax.sharding.use_mesh`` → ``jax.set_mesh``, plus the
-``AxisType`` kwarg that does not exist before 0.5).  Callers — the shard
-hints, their tests — go through these shims instead of pinning one API.
+keep seeing 1 device).  Sharding hints (``nn.shard_hints``) resolve
+against the mesh activated with ``jax.set_mesh``.
 """
 from __future__ import annotations
 
-import contextlib
-
 import jax
-
-
-def make_auto_mesh(shape, axis_names):
-    """``jax.make_mesh`` with Auto axis types where the API supports them.
-
-    On jax ≥ 0.5 hint-style sharding (``with_sharding_constraint`` under an
-    active mesh) wants explicitly-Auto axes; jax 0.4.x has no ``AxisType``
-    at all (referencing ``jax.sharding.AxisType`` raises AttributeError from
-    the deprecation machinery) and every axis is implicitly Auto.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axis_names,
-                             axis_types=(axis_type.Auto,) * len(axis_names))
-    return jax.make_mesh(shape, axis_names)
-
-
-def use_mesh(mesh):
-    """Context manager activating ``mesh`` for sharding-hint resolution.
-
-    Prefers ``jax.set_mesh`` (≥ 0.6), then ``jax.sharding.use_mesh``
-    (0.5.x), then the ``with mesh:`` physical-mesh context (0.4.x) — the
-    three spellings of the same thing across the supported jax range.
-    Always scoped: on versions where ``jax.set_mesh`` is a plain global
-    setter rather than a context manager, exit clears the mesh again so a
-    ``with`` block can't leave hints silently active for later traces.
-    """
-    setter = getattr(jax, "set_mesh", None)
-    if setter is not None:
-        return _set_mesh_scoped(setter, mesh)
-    ctx_use = getattr(jax.sharding, "use_mesh", None)
-    if ctx_use is not None:
-        return ctx_use(mesh)
-    return mesh  # 0.4.x: Mesh is its own context manager
-
-
-@contextlib.contextmanager
-def _set_mesh_scoped(setter, mesh):
-    """Scoped wrapper over ``jax.set_mesh``: nothing mutates until context
-    ENTRY, and on the plain-global-setter variant exit restores whatever
-    mesh was active before (so nested ``use_mesh`` blocks compose instead
-    of clearing the outer mesh)."""
-    prev = None
-    get_prev = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get_prev is not None:
-        try:
-            prev = get_prev()
-        except Exception:  # noqa: BLE001
-            prev = None
-        if prev is not None and not getattr(prev, "axis_names", ()):
-            prev = None
-    ctx = setter(mesh)
-    if hasattr(ctx, "__enter__"):   # set_mesh is itself a context manager
-        with ctx:
-            yield mesh
-        return
-    try:
-        yield mesh
-    finally:
-        setter(prev)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -102,6 +34,15 @@ DEVICE_AXIS = "device"
 FOG_AXIS = "fog"
 
 
+def _auto_mesh(shape, axis_names):
+    """``jax.make_mesh`` with Auto axes.  The engines place the fleet through
+    shard_map specs and NamedShardings; with Explicit axes (the default), a
+    single-device jit that follows a sharded run fails to lower ("Length of
+    device assignment 1 is not equal to the size of the mesh")."""
+    return jax.make_mesh(shape, axis_names, axis_types=(
+        jax.sharding.AxisType.Auto,) * len(axis_names))
+
+
 def make_device_mesh(shards: int | None = None):
     """1-D mesh for the federated fleet's device axis (``EdgeEngine(mesh=...)``).
 
@@ -113,7 +54,7 @@ def make_device_mesh(shards: int | None = None):
     import (see tests/test_shard_engine.py and the CI sharded job).
     """
     n = shards or len(jax.devices())
-    return jax.make_mesh((n,), (DEVICE_AXIS,))
+    return _auto_mesh((n,), (DEVICE_AXIS,))
 
 
 def make_fog_mesh(fog_shards: int | None = None,
@@ -139,8 +80,7 @@ def make_fog_mesh(fog_shards: int | None = None,
     if fog_shards < 1 or device_shards < 1:
         raise ValueError(f"mesh shape ({fog_shards}, {device_shards}) "
                          f"must be positive")
-    return jax.make_mesh((fog_shards, device_shards),
-                         (FOG_AXIS, DEVICE_AXIS))
+    return _auto_mesh((fog_shards, device_shards), (FOG_AXIS, DEVICE_AXIS))
 
 
 def batch_axes(mesh) -> tuple:

@@ -18,37 +18,9 @@ AxisEntry = Union[None, str, Sequence[str]]
 
 
 def _active_mesh():
-    """The mesh hints should resolve against, or None (hints become no-ops).
-
-    jax ≥ 0.5 exposes the active mesh as ``jax.sharding.get_abstract_mesh``
-    (set via ``jax.set_mesh`` / ``use_mesh``); jax 0.4.x only has the
-    ``with mesh:`` physical-mesh context on ``thread_resources`` — probe
-    both so model code works across the supported range (see
-    ``launch.mesh.use_mesh``).
-    """
-    get_abstract = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get_abstract is not None:
-        try:
-            mesh = get_abstract()
-        except Exception:  # noqa: BLE001
-            mesh = None
-        if mesh is not None and getattr(mesh, "axis_names", ()):
-            return mesh
-    try:  # 0.4.x fallback: the `with mesh:` context manager
-        from jax._src import mesh as mesh_lib
-        phys = mesh_lib.thread_resources.env.physical_mesh
-    except Exception:  # noqa: BLE001
-        return None
-    if phys is None or getattr(phys, "empty", True):
-        return None
-    return phys
-
-
-def _mesh_axis_sizes(mesh) -> dict:
-    sizes = getattr(mesh, "axis_sizes", None)
-    if sizes is not None:
-        return dict(zip(mesh.axis_names, sizes))
-    return dict(mesh.shape)  # 0.4.x Mesh: OrderedDict name -> size
+    """The mesh set by ``jax.set_mesh``, or None (hints become no-ops)."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return None if mesh.empty else mesh
 
 
 def _axis_size(mesh, entry: AxisEntry) -> int:
@@ -57,7 +29,7 @@ def _axis_size(mesh, entry: AxisEntry) -> int:
     names = (entry,) if isinstance(entry, str) else tuple(entry)
     size = 1
     for n in names:
-        size *= _mesh_axis_sizes(mesh)[n]
+        size *= mesh.shape[n]
     return size
 
 
@@ -94,7 +66,7 @@ def hint_heads(x, *, batch_axes: AxisEntry = "data", model_axis: str = "model"):
     mesh = _active_mesh()
     if mesh is None or x.ndim != 4:
         return x
-    model_size = _mesh_axis_sizes(mesh).get(model_axis, 1)
+    model_size = mesh.shape.get(model_axis, 1)
     H = x.shape[2]
     if H % model_size == 0:
         return hint(x, batch_axes, None, model_axis, None)

@@ -6,6 +6,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.core import aggregation as agg_mod
+from repro.core import engine as engine_mod
 from repro.core import vpool
 from repro.core.engine import EdgeEngine, stack_device_data
 from repro.core.federated import (FederatedALConfig, Trainer,
@@ -85,6 +87,17 @@ def test_vmapped_engine_matches_legacy_loop(setup):
             assert abs(rv["test_acc"] - rl["test_acc"]) <= 1e-5
     assert abs(rep_v["aggregated_acc"] - rep_l["aggregated_acc"]) <= 1e-5
     assert rep_v["aggregation"]["strategy"] == rep_l["aggregation"]["strategy"]
+
+
+@pytest.mark.parametrize("resolve", [engine_mod.resolve_scorer,
+                                     agg_mod.resolve_aggregate_impl],
+                         ids=["scorer", "aggregate_impl"])
+def test_explicit_pallas_refuses_non_tpu_backend(resolve):
+    """An explicit 'pallas' must not quietly fall back to interpret mode
+    off-TPU; 'pallas_interpret' stays the CPU path."""
+    with pytest.raises(ValueError, match="compiles for TPU"):
+        resolve("pallas")
+    assert resolve("pallas_interpret") == "pallas_interpret"
 
 
 @pytest.mark.slow

@@ -5,13 +5,19 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.launch.hlo_analysis import _is_cross_pod, analyze
-from repro.launch.mesh import make_auto_mesh, use_mesh
 from repro.nn.shard_hints import hint, hint_heads
 
 jax.config.update("jax_platform_name", "cpu")
 
 
 # ---------------------------------------------------------------- hints
+def _auto_mesh():
+    # hint-style sharding (with_sharding_constraint under jax.set_mesh)
+    # needs Auto axes; jax.make_mesh defaults to Explicit
+    return jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
+
+
 def test_hint_noop_without_mesh():
     x = jnp.ones((4, 8))
     y = hint(x, "data", "model")
@@ -21,24 +27,21 @@ def test_hint_noop_without_mesh():
 
 
 def test_hint_inside_mesh_context():
-    # make_auto_mesh / use_mesh pick whichever mesh-context API this jax
-    # version has (AxisType + set_mesh on >=0.5, `with mesh:` on 0.4.x)
-    mesh = make_auto_mesh((1, 1), ("data", "model"))
+    mesh = _auto_mesh()
 
     # axis size 1 divides everything; just verify it traces and is identity
     x = jnp.arange(12.0).reshape(4, 3)
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         y = jax.jit(lambda v: hint(v, "data", None))(x)
     np.testing.assert_allclose(np.asarray(x), np.asarray(y))
 
 
 def test_hint_applies_constraint_inside_mesh_context():
     """The hint must actually lower to a sharding constraint (not silently
-    no-op) when a mesh is active — the regression mode of the 0.4.37
-    AttributeError was hints becoming no-ops everywhere."""
-    mesh = make_auto_mesh((1, 1), ("data", "model"))
+    no-op) when a mesh is active."""
+    mesh = _auto_mesh()
     x = jnp.arange(12.0).reshape(4, 3)
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         from repro.nn.shard_hints import _active_mesh
         assert _active_mesh() is not None
         txt = jax.jit(lambda v: hint(v, "data", None)).lower(x).as_text()
@@ -47,8 +50,8 @@ def test_hint_applies_constraint_inside_mesh_context():
 
 
 def test_hint_skips_nondividing_axis():
-    mesh = make_auto_mesh((1, 1), ("data", "model"))
-    with use_mesh(mesh):
+    mesh = _auto_mesh()
+    with jax.set_mesh(mesh):
         # 7 is not divisible by anything > 1; with axis size 1 it IS
         # divisible — the guard path is exercised via absent axis name
         y = jax.jit(lambda v: hint(v, "absent_axis", None))(jnp.ones((7, 3)))

@@ -21,7 +21,7 @@ from repro.core.engine import EdgeEngine
 from repro.core.federated import FederatedALConfig, Trainer
 from repro.data.digits import make_digit_dataset
 from repro.data.federated_split import federated_split
-from repro.launch.mesh import make_device_mesh
+from repro.launch.mesh import make_device_mesh, make_fog_mesh
 from repro.launch.sharding import shard_engine_state
 
 jax.config.update("jax_platform_name", "cpu")
@@ -113,6 +113,15 @@ def test_shard_engine_state_places_leading_axis(setup):
     assert leaf.sharding.mesh.shape["device"] == jax.device_count()
 
 
+@pytest.mark.parametrize("make", [make_device_mesh, make_fog_mesh])
+def test_fleet_meshes_use_auto_axes(make):
+    """The fleet meshes carry Auto axes: the engines place data through
+    shard_map specs, and an Explicit mesh axis breaks single-device programs
+    that follow a sharded run (exercised in the forced-8-device check)."""
+    mesh = make()
+    assert set(mesh.axis_types) == {jax.sharding.AxisType.Auto}
+
+
 # --------------------------------------------------- forced-8-device check
 _FORCED_SCRIPT = r"""
 import os
@@ -143,6 +152,12 @@ em = EdgeEngine(trainer, cfg, shards, seed_set, test, mesh=make_device_mesh())
 _, _, fm = em.run_rounds_fused(em.init_state(params0), 1)
 for a, b in zip(jax.tree_util.tree_leaves(fv), jax.tree_util.tree_leaves(fm)):
     np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-5)
+# a single-device program over the sharded run's fog model still lowers
+fm1 = jax.device_put(fm, jax.devices()[0])
+x = jax.numpy.asarray(np.stack([s.images[:8] for s in shards]))
+keys = jax.random.split(jax.random.key(1), cfg.num_devices)
+jax.block_until_ready(jax.jit(jax.vmap(
+    lambda xd, kd: trainer.score_logprobs_raw(fm1, xd, kd, 2)))(x, keys))
 print("OK")
 """
 

@@ -128,3 +128,33 @@ def test_hlo_analysis_scan_vs_unroll():
         st = analyze(jax.jit(f).lower(x, w).compile().as_text())
         fl.append(st.flops)
     assert fl[0] == fl[1] == 7 * 2 * 128**3
+
+
+def test_compile_cache_env_var_wins(monkeypatch, tmp_path):
+    """With JAX_COMPILATION_CACHE_DIR set, the helper sets no other path."""
+    from repro.launch.compile_cache import ENV_VAR, enable_compile_cache
+
+    monkeypatch.setenv(ENV_VAR, str(tmp_path))
+    before = jax.config.jax_compilation_cache_dir
+    assert enable_compile_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == before
+
+
+def test_compile_cache_default_is_fixed_and_tpu_only(monkeypatch):
+    """Unset, the cache path is one fixed, git-ignored directory of the
+    checkout, and off-TPU the helper leaves the cache off."""
+    import os
+
+    from repro.launch.compile_cache import (DEFAULT_DIR, ENV_VAR,
+                                            enable_compile_cache)
+
+    monkeypatch.delenv(ENV_VAR, raising=False)
+    root = DEFAULT_DIR.parent
+    assert DEFAULT_DIR.name == ".jax_cache"
+    assert os.path.exists(root / "pyproject.toml")
+    with open(root / ".gitignore") as f:
+        assert ".jax_cache/" in f.read().split()
+    if jax.default_backend() != "tpu":
+        before = jax.config.jax_compilation_cache_dir
+        assert enable_compile_cache() is None
+        assert jax.config.jax_compilation_cache_dir == before
